@@ -12,7 +12,7 @@ The TPU-native translation of the paper's two paths:
   out *on device* (more copies). This is the baseline whose cost the
   protocol deletes.
 
-Both produce identical column arrays (tests assert allclose), so the rest of
+Both produce bit-identical column arrays (tests assert it), so the rest of
 the stack — the input pipeline feeding ``train_step`` — is transport-
 agnostic.
 """
@@ -41,12 +41,19 @@ class DeviceBatch:
         return self.columns[name]
 
 
-def _col_array(col) -> np.ndarray:
+def _check_device_column(col) -> None:
+    """Refuse, by name, a column the device would not hold bit for bit:
+    variable-length, or a 64-bit dtype with x64 off (silently narrowed)."""
     if col.field.varlen:
         raise ValueError(
             f"column {col.field.name!r} is variable-length; device transport "
             "carries fixed-width (tokenized/numeric) columns")
-    return col.values
+    dtype = col.values.dtype
+    if jax.dtypes.canonicalize_dtype(dtype) != dtype:
+        raise ValueError(
+            f"column {col.field.name!r} is {dtype}, which the device would "
+            f"hold as {jax.dtypes.canonicalize_dtype(dtype)}; device "
+            "transport carries only columns it lands bit for bit")
 
 
 def batch_to_device(batch: RecordBatch, mesh: Mesh | None = None,
@@ -54,7 +61,8 @@ def batch_to_device(batch: RecordBatch, mesh: Mesh | None = None,
     """Zero-staging path: per-column device_put with explicit sharding."""
     cols: dict[str, jax.Array] = {}
     for field, col in zip(batch.schema, batch.columns):
-        arr = _col_array(col)
+        _check_device_column(col)
+        arr = col.values
         if mesh is not None:
             spec = specs[field.name] if isinstance(specs, Mapping) else (specs or P())
             cols[field.name] = jax.device_put(arr, NamedSharding(mesh, spec))
@@ -66,6 +74,8 @@ def batch_to_device(batch: RecordBatch, mesh: Mesh | None = None,
 def batch_to_device_packed(batch: RecordBatch, mesh: Mesh | None = None,
                            specs: Mapping[str, P] | P | None = None) -> DeviceBatch:
     """Baseline path: pack → single transfer → on-device slice-out."""
+    for col in batch.columns:
+        _check_device_column(col)
     wire = serialize.pack(batch)  # host staging copy (the overhead)
     if mesh is not None:
         # the packed buffer is replicated (it cannot be column-sharded —

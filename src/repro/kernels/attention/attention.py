@@ -20,6 +20,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from .. import interpret_mode
+
 NEG_INF = -1e30
 BLK_Q = 128
 BLK_K = 128
@@ -27,20 +29,14 @@ BLK_K = 128
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, causal: bool, sk: int,
                   blk_k: int, scale: float):
-    # index the unit batch axis with a length-1 slice, not a bare int:
-    # jax 0.4.37's interpret-mode discharge rule only accepts Slice/array
-    # indices inside pl.load/pl.store
-    q = pl.load(q_ref, (pl.ds(0, 1), slice(None), slice(None))
-                )[0].astype(jnp.float32) * scale      # (BLK_Q, hd)
+    q = q_ref[0].astype(jnp.float32) * scale          # (BLK_Q, hd)
     q_block = pl.program_id(1)
     q_pos = q_block * BLK_Q + jax.lax.broadcasted_iota(jnp.int32, (BLK_Q, 1), 0)
 
     def body(i, carry):
         m_prev, l_prev, acc = carry
-        k_blk = pl.load(k_ref, (pl.ds(0, 1), pl.ds(i * blk_k, blk_k),
-                                slice(None)))[0].astype(jnp.float32)
-        v_blk = pl.load(v_ref, (pl.ds(0, 1), pl.ds(i * blk_k, blk_k),
-                                slice(None)))[0].astype(jnp.float32)
+        k_blk = k_ref[0, pl.ds(i * blk_k, blk_k), :].astype(jnp.float32)
+        v_blk = v_ref[0, pl.ds(i * blk_k, blk_k), :].astype(jnp.float32)
         s = q @ k_blk.T                                # (BLK_Q, blk_k) VMEM
         if causal:
             k_pos = i * blk_k + jax.lax.broadcasted_iota(
@@ -58,15 +54,22 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, causal: bool, sk: int,
     l0 = jnp.zeros((BLK_Q, 1), jnp.float32)
     a0 = jnp.zeros((BLK_Q, q.shape[-1]), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, sk // blk_k, body, (m0, l0, a0))
-    out = (acc / jnp.maximum(l, 1e-37)).astype(o_ref.dtype)
-    pl.store(o_ref, (pl.ds(0, 1), slice(None), slice(None)), out[None])
+    o_ref[0] = (acc / jnp.maximum(l, 1e-37)).astype(o_ref.dtype)
+
+
+def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                    causal: bool = True) -> jax.Array:
+    """q: (BH, Sq, hd); k/v: (BH, Sk, hd); heads pre-expanded (GQA handled
+    by the ops wrapper). Sq % 128 == 0, Sk % 128 == 0. The whole K/V of one
+    head sits in VMEM per query block, which bounds Sk (2048 and 8192
+    compile for v5e; 32768 exceeds its VMEM)."""
+    return _flash_attention(q, k, v, causal=causal,
+                            interpret=interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "interpret"))
-def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, interpret: bool = True) -> jax.Array:
-    """q: (BH, Sq, hd); k/v: (BH, Sk, hd); heads pre-expanded (GQA handled
-    by the ops wrapper). Sq % 128 == 0, Sk % 128 == 0."""
+def _flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                     causal: bool, interpret: bool) -> jax.Array:
     BH, Sq, hd = q.shape
     Sk = k.shape[1]
     scale = 1.0 / np.sqrt(hd)

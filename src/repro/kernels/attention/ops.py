@@ -9,7 +9,7 @@ from .ref import attention_ref  # noqa: F401 (re-export oracle)
 
 
 def flash_gqa(q: jax.Array, k: jax.Array, v: jax.Array, *,
-              causal: bool = True, interpret: bool = True) -> jax.Array:
+              causal: bool = True) -> jax.Array:
     """q: (B, Sq, H, hd); k/v: (B, Sk, KV, hd) with H % KV == 0.
     Returns (B, Sq, H, hd)."""
     B, Sq, H, hd = q.shape
@@ -20,5 +20,5 @@ def flash_gqa(q: jax.Array, k: jax.Array, v: jax.Array, *,
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, hd)
     kf = kx.transpose(0, 2, 1, 3).reshape(B * H, k.shape[1], hd)
     vf = vx.transpose(0, 2, 1, 3).reshape(B * H, v.shape[1], hd)
-    out = flash_attention(qf, kf, vf, causal=causal, interpret=interpret)
+    out = flash_attention(qf, kf, vf, causal=causal)
     return out.reshape(B, H, Sq, hd).transpose(0, 2, 1, 3)

@@ -33,19 +33,18 @@ def inverse_routing(seg_lens: list[int], max_tiles: int) -> np.ndarray:
     return inv
 
 
-def pack_segments(segments: list[np.ndarray], *,
-                  interpret: bool = True) -> tuple[jax.Array, list[int]]:
+def pack_segments(segments: list[np.ndarray]) -> tuple[jax.Array, list[int]]:
     """Serialize: list of arbitrary-dtype buffers -> (packed uint8 tiles,
     per-segment byte lengths). packed shape: (n_out_tiles, 32, 128)."""
     staged, seg_lens = stage_segments(segments)
     seg_ids, tile_ids = routing([int(n) for n in seg_lens])
     packed = pack_tiles(jnp.asarray(staged), jnp.asarray(seg_ids),
-                        jnp.asarray(tile_ids), interpret=interpret)
+                        jnp.asarray(tile_ids))
     return packed, [int(n) for n in seg_lens]
 
 
-def unpack_segments(packed: jax.Array, seg_lens: list[int], *,
-                    interpret: bool = True) -> list[np.ndarray]:
+def unpack_segments(packed: jax.Array,
+                    seg_lens: list[int]) -> list[np.ndarray]:
     """Deserialize: packed tiles + size vector -> per-segment uint8 buffers
     (caller re-views dtypes, as in Arrow's buffers+sizes+dtypes assembly)."""
     max_tiles = max(tiles_for(n) for n in seg_lens)
@@ -53,7 +52,7 @@ def unpack_segments(packed: jax.Array, seg_lens: list[int], *,
     zero = jnp.zeros((1, TILE_ROWS, TILE_LANES), jnp.uint8)
     padded = jnp.concatenate([packed, zero], axis=0)
     ragged = unpack_tiles(padded, jnp.asarray(inv), n_seg=len(seg_lens),
-                          max_tiles=max_tiles, interpret=interpret)
+                          max_tiles=max_tiles)
     out = []
     for i, n in enumerate(seg_lens):
         flat = np.asarray(ragged[i]).reshape(-1)

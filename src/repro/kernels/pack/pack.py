@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import interpret_mode
 from .ref import TILE_LANES, TILE_ROWS
 
 
@@ -28,15 +29,20 @@ def _copy_kernel(seg_ids, tile_ids, src_ref, out_ref):
     out_ref[...] = src_ref[0]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def pack_tiles(src: jax.Array, seg_ids: jax.Array, tile_ids: jax.Array,
-               *, interpret: bool = True) -> jax.Array:
+def pack_tiles(src: jax.Array, seg_ids: jax.Array,
+               tile_ids: jax.Array) -> jax.Array:
     """Gather routed tiles: out[t] = src[seg_ids[t], tile_ids[t]].
 
     src: (n_seg, max_tiles, 32, 128) uint8
     seg_ids/tile_ids: (n_out_tiles,) int32 scalar-prefetch routing table
     -> (n_out_tiles, 32, 128) uint8 packed buffer
     """
+    return _pack_tiles(src, seg_ids, tile_ids, interpret=interpret_mode())
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _pack_tiles(src: jax.Array, seg_ids: jax.Array, tile_ids: jax.Array,
+                *, interpret: bool) -> jax.Array:
     n_out = seg_ids.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -56,10 +62,8 @@ def pack_tiles(src: jax.Array, seg_ids: jax.Array, tile_ids: jax.Array,
     )(seg_ids, tile_ids, src)
 
 
-@functools.partial(jax.jit, static_argnames=("n_seg", "max_tiles", "interpret"))
 def unpack_tiles(packed: jax.Array, gather_ids: jax.Array,
-                 *, n_seg: int, max_tiles: int,
-                 interpret: bool = True) -> jax.Array:
+                 *, n_seg: int, max_tiles: int) -> jax.Array:
     """Inverse gather: out[s, t] = packed[gather_ids[s*max_tiles + t]].
 
     ``gather_ids`` is the *inverse* routing table (see
@@ -68,6 +72,13 @@ def unpack_tiles(packed: jax.Array, gather_ids: jax.Array,
     gather — every output tile is written exactly once, no scatter hazards.
     packed: (n_out_tiles + 1, 32, 128) with packed[-1] == 0.
     """
+    return _unpack_tiles(packed, gather_ids, n_seg=n_seg,
+                         max_tiles=max_tiles, interpret=interpret_mode())
+
+
+@functools.partial(jax.jit, static_argnames=("n_seg", "max_tiles", "interpret"))
+def _unpack_tiles(packed: jax.Array, gather_ids: jax.Array, *, n_seg: int,
+                  max_tiles: int, interpret: bool) -> jax.Array:
     n_total = n_seg * max_tiles
 
     def kernel(gather_ids, packed_ref, out_ref):

@@ -9,17 +9,10 @@ intra-pod on ICI).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:                                  # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:                   # pinned jax 0.4.x: Auto is the default
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -22,6 +22,7 @@ from ..core import Fabric, ThallusTransport
 from ..models import decode as decode_fn
 from ..models import init_params, make_rules, mesh_context, prefill
 from ..serving import Batcher, Request, completions_to_batch
+from .cache import enable_compile_cache
 from .mesh import make_host_mesh
 
 
@@ -34,6 +35,7 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=12)
     ap.add_argument("--max-new", type=int, default=8)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

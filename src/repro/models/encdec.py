@@ -15,8 +15,8 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
-from .layers import (chunked_attention, decode_attention, layer_norm,
-                     plain_mlp)
+from .layers import (chunked_attention, decode_attention, embedding_init,
+                     layer_norm, plain_mlp)
 from .transformer import mask_padded_vocab
 from .sharding import constrain
 
@@ -54,7 +54,8 @@ def init_encdec_params(cfg: ArchConfig, key: jax.Array, dtype=jnp.float32) -> Pa
         }
 
     return {
-        "embed": jax.random.normal(next(ks), (cfg.padded_vocab, D), dtype),
+        # the decoder's readout is always the embedding table
+        "embed": embedding_init(next(ks), cfg.padded_vocab, D, True, dtype),
         "enc_pos": jax.random.normal(next(ks), (T_enc, D), dtype) * 0.01,
         "dec_pos": jax.random.normal(next(ks), (DEC_POS_MAX, D), dtype) * 0.01,
         "encoder": {"attn": attn(Le), "mlp": mlp(Le),

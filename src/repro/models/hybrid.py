@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
 from .layers import (apply_rope, chunked_attention, decode_attention,
-                     gated_mlp, rms_norm)
+                     embedding_init, gated_mlp, rms_norm, rms_norm_weight)
 from .mamba2 import (init_mamba_layer_params, mamba_block, mamba_decode_block,
                      ssm_dims)
 from .sharding import constrain
@@ -56,15 +56,16 @@ def init_hybrid_params(cfg: ArchConfig, key: jax.Array, dtype=jnp.float32) -> Pa
             "wd": jax.random.normal(next(ks), (F, 2 * D), dtype)
                   * (1.0 / math.sqrt(F)),
         },
-        "ln1": jnp.zeros((2 * D,), dtype),
-        "ln2": jnp.zeros((2 * D,), dtype),
+        "ln1": rms_norm_weight((2 * D,), dtype),
+        "ln2": rms_norm_weight((2 * D,), dtype),
         "down": jax.random.normal(next(ks), (2 * D, D), dtype) * s2d,
     }
     params: Params = {
-        "embed": jax.random.normal(k1, (cfg.padded_vocab, D), dtype),
+        "embed": embedding_init(k1, cfg.padded_vocab, D, cfg.tie_embeddings,
+                                dtype),
         "mamba_layers": init_mamba_layer_params(cfg, k2, cfg.num_layers, dtype),
         "shared": shared,
-        "final_norm": jnp.zeros((D,), dtype),
+        "final_norm": rms_norm_weight((D,), dtype),
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = (jax.random.normal(k4, (D, cfg.padded_vocab), dtype)

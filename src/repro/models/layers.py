@@ -34,6 +34,22 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6,
     return out.astype(dtype)
 
 
+def rms_norm_weight(shape, dtype, zero_centered: bool = False) -> jax.Array:
+    """Identity RMSNorm weight: 0 for the ``(1+w)`` form, 1 for ``x*w``."""
+    return (jnp.zeros if zero_centered else jnp.ones)(shape, dtype)
+
+
+def embedding_init(key: jax.Array, vocab: int, d_model: int, tied: bool,
+                   dtype) -> jax.Array:
+    """Embedding table. Untied, it is only looked up and starts at std 1.
+    Tied, it is the readout too: rows at std 1/(2*sqrt(d_model)) give
+    logits of std 1/2 on a unit-RMS final norm, so an untrained model's
+    loss starts near ln(vocab) + 1/8 (at std 1 the logits have std
+    sqrt(d_model) and the loss is off by hundreds of nats)."""
+    std = 0.5 / np.sqrt(d_model) if tied else 1.0
+    return jax.random.normal(key, (vocab, d_model), dtype) * std
+
+
 def layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array,
                eps: float = 1e-5) -> jax.Array:
     dtype = x.dtype

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig, SSMConfig
-from .layers import rms_norm
+from .layers import embedding_init, rms_norm, rms_norm_weight
 from .sharding import constrain
 
 Params = dict[str, Any]
@@ -49,10 +49,10 @@ def init_mamba_layer_params(cfg: ArchConfig, key: jax.Array, L: int,
             jnp.linspace(1.0, 16.0, H, dtype=jnp.float32)[None], (L, H)).copy()),
         "D": jnp.ones((L, H), jnp.float32),
         "dt_bias": jnp.zeros((L, H), jnp.float32),
-        "norm": jnp.zeros((L, d_inner), dtype),
+        "norm": rms_norm_weight((L, d_inner), dtype),
         "out_proj": jax.random.normal(next(ks), (L, d_inner, D), dtype)
                     * (1.0 / math.sqrt(d_inner)),
-        "ln": jnp.zeros((L, D), dtype),
+        "ln": rms_norm_weight((L, D), dtype),
     }
 
 
@@ -111,7 +111,9 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, B_: jax.Array,
         dAcum = jnp.cumsum(dA, axis=2)                     # within-chunk
         seg = dAcum[:, :, :, None, :] - dAcum[:, :, None, :, :]
         causal = jnp.tril(jnp.ones((Q, Q), bool))
-        Lmat = jnp.where(causal[None, None, :, :, None], jnp.exp(seg), 0.0)
+        # mask before exp: above the diagonal seg > 0 and exp overflows,
+        # and a masked inf still sends inf * 0 = nan back through the where
+        Lmat = jnp.exp(jnp.where(causal[None, None, :, :, None], seg, -jnp.inf))
 
         # intra-chunk (duality: masked attention within the chunk)
         CB = jnp.einsum("bclgn,bcsgn->bclsg", Cc, Bc)      # (B,nc,l,s,G)
@@ -219,9 +221,10 @@ def mamba_decode_block(cfg: ArchConfig, p: Params, u: jax.Array,
 def init_mamba_params(cfg: ArchConfig, key: jax.Array, dtype=jnp.float32) -> Params:
     k1, k2, k3 = jax.random.split(key, 3)
     params: Params = {
-        "embed": jax.random.normal(k1, (cfg.padded_vocab, cfg.d_model), dtype),
+        "embed": embedding_init(k1, cfg.padded_vocab, cfg.d_model,
+                                cfg.tie_embeddings, dtype),
         "layers": init_mamba_layer_params(cfg, k2, cfg.num_layers, dtype),
-        "final_norm": jnp.zeros((cfg.d_model,), dtype),
+        "final_norm": rms_norm_weight((cfg.d_model,), dtype),
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = (jax.random.normal(k3, (cfg.d_model, cfg.padded_vocab),

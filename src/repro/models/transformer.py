@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
 from .layers import (apply_rope, chunked_attention, decode_attention,
-                     gated_mlp, rms_norm)
+                     embedding_init, gated_mlp, rms_norm, rms_norm_weight)
 from .moe import init_moe_params, moe_ffn
 from .sharding import constrain
 
@@ -46,13 +46,14 @@ def init_transformer_params(cfg: ArchConfig, key: jax.Array,
               * (1.0 / math.sqrt(H * hd)),
     }
     if cfg.qk_norm:
-        attn["q_norm"] = jnp.zeros((L, hd), dtype)
-        attn["k_norm"] = jnp.zeros((L, hd), dtype)
+        attn["q_norm"] = rms_norm_weight((L, hd), dtype)
+        attn["k_norm"] = rms_norm_weight((L, hd), dtype)
 
+    zc = cfg.zero_centered_norm
     layers: Params = {
         "attn": attn,
-        "ln1": jnp.zeros((L, D), dtype),
-        "ln2": jnp.zeros((L, D), dtype),
+        "ln1": rms_norm_weight((L, D), dtype, zc),
+        "ln2": rms_norm_weight((L, D), dtype, zc),
     }
     if cfg.moe is not None:
         moe_keys = jax.random.split(next(ks), L)
@@ -67,9 +68,9 @@ def init_transformer_params(cfg: ArchConfig, key: jax.Array,
         }
 
     params: Params = {
-        "embed": jax.random.normal(next(ks), (V, D), dtype),
+        "embed": embedding_init(next(ks), V, D, cfg.tie_embeddings, dtype),
         "layers": layers,
-        "final_norm": jnp.zeros((D,), dtype),
+        "final_norm": rms_norm_weight((D,), dtype, zc),
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = jax.random.normal(next(ks), (D, V), dtype) * s_d

@@ -60,6 +60,25 @@ def test_take_1d(rng):
     np.testing.assert_array_equal(np.asarray(take_column(vals, idx)), vals[idx])
 
 
+def test_take_spans_grid_steps_and_lane_tiles(rng):
+    """More selected rows than one grid step gathers, from a column two
+    128-lane tiles wide."""
+    vals = rng.standard_normal((5000, 256)).astype(np.float32)
+    idx = rng.integers(0, 5000, 3000).astype(np.int32)
+    got = np.asarray(take_column(vals, idx))
+    np.testing.assert_array_equal(got, vals[idx])
+
+
+def test_take_out_of_range_reads_like_ref(rng):
+    """Negative and past-the-end indices read what the jnp oracle reads,
+    and never leave the column."""
+    vals = rng.standard_normal((50, 3)).astype(np.float32)
+    idx = np.array([-1, -50, 49, 50, 1000, -1000, 0], np.int32)
+    got = np.asarray(take_column(vals, idx))
+    ref = np.asarray(take_ref(jnp.asarray(vals), jnp.asarray(idx)))
+    np.testing.assert_array_equal(got, ref)
+
+
 @pytest.mark.parametrize("n", [1, 8, 100, 1024, 4096, 10000])
 def test_bitmap_expand_matches_ref(rng, n):
     mask = rng.integers(0, 2, n).astype(bool)

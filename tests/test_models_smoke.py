@@ -64,6 +64,31 @@ def test_train_step(arch, rng):
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
+def test_fresh_model_has_live_signal(arch, rng):
+    """An untrained model's first loss is not the flat ln(vocab) of an
+    all-zero forward, and every parameter leaf gets a gradient: no norm
+    weight starts at a zero that multiplies its input away."""
+    cfg = get_config(arch).reduced()
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    batch = _batch(cfg, rng)
+    batch["labels"] = jnp.asarray(
+        rng.integers(0, cfg.vocab_size, batch["tokens"].shape), jnp.int32)
+    loss, grads = jax.value_and_grad(
+        lambda p: loss_fn(cfg, p, batch, remat="none"))(params)
+    gap = float(loss) - np.log(cfg.vocab_size)
+    assert abs(gap) > 1e-2, f"{arch}: loss {float(loss)} is ln(vocab)"
+    if cfg.tie_embeddings:      # the readout starts close to uniform
+        assert abs(gap) < 0.5, f"{arch}: loss {float(loss)}"
+    leaves = jax.tree_util.tree_leaves_with_path(grads)
+    bad = [jax.tree_util.keystr(p) for p, g in leaves
+           if not np.isfinite(np.asarray(g)).all()]
+    assert not bad, f"{arch}: non-finite gradient in {bad}"
+    dead = [jax.tree_util.keystr(p) for p, g in leaves
+            if not np.any(np.asarray(g))]
+    assert not dead, f"{arch}: no gradient reaches {dead}"
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS)
 def test_prefill_then_decode(arch, rng):
     cfg = get_config(arch).reduced()
     params = init_params(cfg, jax.random.PRNGKey(0))

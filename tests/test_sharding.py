@@ -90,15 +90,37 @@ def test_cache_specs_align(arch):
             assert dim % n == 0
 
 
+def _bits(x) -> np.ndarray:
+    x = np.asarray(x)
+    return x.view(np.uint8).reshape(x.shape + (x.dtype.itemsize,))
+
+
 def test_device_transport_parity(rng):
-    """thallus path and packed path land identical column arrays."""
-    sch = schema(("a", "float32"), ("b", "int32"))
-    batch = batch_from_arrays(sch, [rng.standard_normal(256).astype(np.float32),
-                                    rng.integers(0, 9, 256).astype(np.int32)])
+    """thallus path and packed path land bit-identical column arrays, equal
+    bit for bit to the host columns (NaNs and signed zeros included)."""
+    sch = schema(("a", "float32"), ("b", "int32"), ("c", "float16"))
+    a = rng.standard_normal(256).astype(np.float32)
+    a[:3] = [np.nan, -0.0, np.inf]
+    batch = batch_from_arrays(sch, [a, rng.integers(0, 9, 256).astype(np.int32),
+                                    rng.standard_normal(256).astype(np.float16)])
     th = batch_to_device(batch)
     pk = batch_to_device_packed(batch)
-    np.testing.assert_allclose(np.asarray(th["a"]), np.asarray(pk["a"]))
-    np.testing.assert_array_equal(np.asarray(th["b"]), np.asarray(pk["b"]))
+    for col in batch.columns:
+        name = col.field.name
+        assert th[name].dtype == pk[name].dtype == col.values.dtype
+        np.testing.assert_array_equal(_bits(th[name]), _bits(col.values))
+        np.testing.assert_array_equal(_bits(pk[name]), _bits(th[name]))
+
+
+@pytest.mark.parametrize("to_device", [batch_to_device, batch_to_device_packed])
+def test_device_transport_refuses_inexact_column(rng, to_device):
+    """A float64 column would land narrowed to float32 with x64 off: both
+    device paths refuse it by name instead."""
+    sch = schema(("ok", "float32"), ("wide", "float64"))
+    batch = batch_from_arrays(sch, [rng.standard_normal(64).astype(np.float32),
+                                    rng.standard_normal(64)])
+    with pytest.raises(ValueError, match="'wide' is float64"):
+        to_device(batch)
 
 
 def test_shape_bytes():

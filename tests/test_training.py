@@ -81,10 +81,15 @@ def test_microbatch_equivalence(rng):
     out2, m2 = make_train_step(cfg, TrainConfig(optimizer=opt, remat="none",
                                                 microbatches=2))(s2, batch)
     np.testing.assert_allclose(float(m1["loss"]), float(m2["loss"]), rtol=1e-5)
-    for a, b in zip(jax.tree.leaves(out1["params"]),
-                    jax.tree.leaves(out2["params"])):
+    np.testing.assert_allclose(float(m1["grad_norm"]), float(m2["grad_norm"]),
+                               rtol=1e-5)
+    # the first moment after one step is (1 - beta1) x the clipped gradient,
+    # linear in it; the updated params are not: Adam's g / (|g| + eps) turns
+    # f32 rounding of a gradient near eps into a different step
+    for a, b in zip(jax.tree.leaves(out1["opt"]["m"]),
+                    jax.tree.leaves(out2["opt"]["m"])):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=2e-5)
+                                   rtol=2e-4, atol=1e-8)
 
 
 def test_checkpoint_restart_loss_continuity(tmp_path, rng):

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs import spans
 from . import serialize
 from .recordbatch import RecordBatch
 
@@ -60,14 +61,19 @@ def batch_to_device(batch: RecordBatch, mesh: Mesh | None = None,
                     specs: Mapping[str, P] | P | None = None) -> DeviceBatch:
     """Zero-staging path: per-column device_put with explicit sharding."""
     cols: dict[str, jax.Array] = {}
-    for field, col in zip(batch.schema, batch.columns):
-        _check_device_column(col)
-        arr = col.values
-        if mesh is not None:
-            spec = specs[field.name] if isinstance(specs, Mapping) else (specs or P())
-            cols[field.name] = jax.device_put(arr, NamedSharding(mesh, spec))
-        else:
-            cols[field.name] = jax.device_put(arr)
+    with spans.span(spans.LAND, rows=batch.num_rows,
+                    columns=batch.num_columns,
+                    bytes=sum(c.values.nbytes for c in batch.columns)):
+        for field, col in zip(batch.schema, batch.columns):
+            _check_device_column(col)
+            arr = col.values
+            if mesh is not None:
+                spec = (specs[field.name] if isinstance(specs, Mapping)
+                        else (specs or P()))
+                cols[field.name] = jax.device_put(arr,
+                                                  NamedSharding(mesh, spec))
+            else:
+                cols[field.name] = jax.device_put(arr)
     return DeviceBatch(cols, batch.num_rows)
 
 

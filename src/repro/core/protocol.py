@@ -34,6 +34,7 @@ from typing import Callable, Iterator, Protocol, Sequence
 
 import numpy as np
 
+from ..obs import spans
 from . import bulk as bulk_mod
 from .fabric import Fabric
 from .recordbatch import RecordBatch
@@ -139,20 +140,21 @@ class ThallusServer:
 
     # ------------------------------------------------------------ init_scan
     def init_scan(self, sql: str, dataset: str, start_batch: int = 0) -> ScanHandle:
-        self._check_alive()
-        reader = self.engine.execute(sql, dataset)
-        uid = str(_uuid.uuid4())
-        now = self._now()
-        entry = _ReaderEntry(reader=reader, schema=reader.schema,
-                             created_at=now, last_activity=now)
-        # resumability: fast-forward a restarted client
-        for _ in range(start_batch):
-            if reader.read_next() is None:
-                break
-            entry.batches_sent += 1
-        self.reader_map[uid] = entry
-        self.fabric.rpc(len(sql) + len(dataset) + 64)
-        return ScanHandle(uid, entry.schema)
+        with spans.span(spans.INIT_SCAN, start_batch=start_batch):
+            self._check_alive()
+            reader = self.engine.execute(sql, dataset)
+            uid = str(_uuid.uuid4())
+            now = self._now()
+            entry = _ReaderEntry(reader=reader, schema=reader.schema,
+                                 created_at=now, last_activity=now)
+            # resumability: fast-forward a restarted client
+            for _ in range(start_batch):
+                if reader.read_next() is None:
+                    break
+                entry.batches_sent += 1
+            self.reader_map[uid] = entry
+            self.fabric.rpc(len(sql) + len(dataset) + 64)
+            return ScanHandle(uid, entry.schema)
 
     # -------------------------------------------------------------- iterate
     def iterate(self, uid: str,
@@ -161,29 +163,32 @@ class ThallusServer:
                 max_batches: int | None = None) -> int:
         """Walk the reader; for each batch expose a read-only bulk and invoke
         the client's do_rdma. Returns number of batches shipped."""
-        self._check_alive()
-        entry = self._entry(uid)
-        entry.touch(self._now())
-        shipped = 0
-        while max_batches is None or shipped < max_batches:
-            batch = entry.reader.read_next()
-            if batch is None:
-                break
-            handle = bulk_mod.expose_batch(batch, mode="read_only")
-            sizes = bulk_mod.size_vectors(batch)
-            self.fabric.rpc(64 + 8 * sum(len(v) for v in sizes))  # control msg
-            do_rdma(batch.num_rows, sizes, handle)
-            entry.batches_sent += 1
+        with spans.span(spans.ITERATE):
+            self._check_alive()
+            entry = self._entry(uid)
             entry.touch(self._now())
-            shipped += 1
-            if self._crash_after is not None:
-                self._crash_after -= 1
-                if self._crash_after <= 0:
-                    self._die()
-                    raise ServerCrashedError(
-                        f"server died mid-iterate after shipping {shipped} "
-                        "batch(es) of this lease")
-        return shipped
+            shipped = 0
+            while max_batches is None or shipped < max_batches:
+                batch = entry.reader.read_next()
+                if batch is None:
+                    break
+                with spans.span(spans.EXPOSE, rows=batch.num_rows,
+                                segments=3 * batch.num_columns):
+                    handle = bulk_mod.expose_batch(batch, mode="read_only")
+                    sizes = bulk_mod.size_vectors(batch)
+                    self.fabric.rpc(64 + 8 * sum(len(v) for v in sizes))
+                do_rdma(batch.num_rows, sizes, handle)
+                entry.batches_sent += 1
+                entry.touch(self._now())
+                shipped += 1
+                if self._crash_after is not None:
+                    self._crash_after -= 1
+                    if self._crash_after <= 0:
+                        self._die()
+                        raise ServerCrashedError(
+                            f"server died mid-iterate after shipping "
+                            f"{shipped} batch(es) of this lease")
+            return shipped
 
     # ----------------------------------------------------------- next_batch
     def next_batch(self, uid: str) -> RecordBatch | None:
@@ -201,10 +206,11 @@ class ThallusServer:
 
     # ------------------------------------------------------------- finalize
     def finalize(self, uid: str) -> None:
-        entry = self._entry(uid)
-        entry.finalized = True
-        del self.reader_map[uid]
-        self.fabric.rpc(64)
+        with spans.span(spans.FINALIZE):
+            entry = self._entry(uid)
+            entry.finalized = True
+            del self.reader_map[uid]
+            self.fabric.rpc(64)
 
     # ------------------------------------------------------------ utilities
     def _entry(self, uid: str) -> _ReaderEntry:
@@ -260,7 +266,8 @@ class ThallusClient:
         self.batches.append(batch)
         self.stats.append(stats)
         if self.sink is not None:
-            self.sink(batch)
+            with spans.span(spans.SINK, rows=batch.num_rows):
+                self.sink(batch)
         return stats
 
     # ------------------------------------------------------------ full run
@@ -270,11 +277,13 @@ class ThallusClient:
 
         ``start_batch``/``max_batches`` bound the scan to a batch range —
         a backup request for one batch pulls exactly one batch."""
-        handle = self.server.init_scan(sql, dataset, start_batch=start_batch)
-        self._schema = handle.schema
-        self.server.iterate(handle.uuid, self.do_rdma,
-                            max_batches=max_batches)
-        self.server.finalize(handle.uuid)
+        with spans.span(spans.SCAN):
+            handle = self.server.init_scan(sql, dataset,
+                                           start_batch=start_batch)
+            self._schema = handle.schema
+            self.server.iterate(handle.uuid, self.do_rdma,
+                                max_batches=max_batches)
+            self.server.finalize(handle.uuid)
         return self.batches
 
     def transport_seconds(self) -> float:

@@ -17,6 +17,7 @@ from __future__ import annotations
 import dataclasses
 import time
 
+from ..obs import spans
 from . import bulk as bulk_mod
 from . import serialize
 from .fabric import Fabric, WireStats
@@ -53,25 +54,28 @@ def rdma_pull_batch(fabric: Fabric, schema, num_rows: int,
 
     Returns ``(batch, local_handle, stats)``; pooled callers release
     ``local_handle`` once the batch is consumed."""
-    stats = TransportStats()
-    t0 = time.perf_counter()
-    if pool is not None:
-        local = pool.acquire(remote.descs)
-    else:
-        local = bulk_mod.allocate_like(remote.descs, pin=pin)
-    stats.alloc_s = time.perf_counter() - t0
-    try:
-        stats.wire = fabric.rdma_pull(remote.segments, local.segments,
-                                      registered=local.registered)
+    with spans.span(spans.PULL, rows=num_rows, bytes=remote.total_bytes,
+                    segments=remote.num_segments):
+        stats = TransportStats()
         t0 = time.perf_counter()
-        batch = bulk_mod.assemble_batch(schema, num_rows, local.segments)
-        stats.deserialize_s = time.perf_counter() - t0
-    except BaseException:
-        # a failed pull must hand its checkout back, or fault-resume loops
-        # leak one slab set per fault
         if pool is not None:
-            pool.release(local)
-        raise
+            local = pool.acquire(remote.descs)
+        else:
+            local = bulk_mod.allocate_like(remote.descs, pin=pin)
+        stats.alloc_s = time.perf_counter() - t0
+        try:
+            stats.wire = fabric.rdma_pull(remote.segments, local.segments,
+                                          registered=local.registered)
+            t0 = time.perf_counter()
+            batch = bulk_mod.assemble_batch(schema, num_rows,
+                                            local.segments)
+            stats.deserialize_s = time.perf_counter() - t0
+        except BaseException:
+            # a failed pull must hand its checkout back, or fault-resume
+            # loops leak one slab set per fault
+            if pool is not None:
+                pool.release(local)
+            raise
     return batch, local, stats
 
 

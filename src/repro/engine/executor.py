@@ -16,6 +16,7 @@ import numpy as np
 from ..core.recordbatch import (Column, RecordBatch, batch_from_arrays,
                                 pack_validity)
 from ..core.schema import Field, Schema
+from ..obs import spans
 from .expressions import filter_mask
 from .sql import Query, SelectItem, parse
 from .table import Catalog, Table
@@ -55,8 +56,9 @@ class Engine:
 
     # -- QueryEngine protocol ------------------------------------------------
     def execute(self, sql: str, dataset: str) -> QueryReader:
-        query = parse(sql)
-        table = self.catalog.get(dataset)
+        with spans.span(spans.ENGINE_PLAN):
+            query = parse(sql)
+            table = self.catalog.get(dataset)
         if query.is_aggregate:
             return self._execute_aggregate(query, table)
         return self._execute_scan(query, table)
@@ -84,15 +86,21 @@ class Engine:
             remaining = query.limit
             for batch in table.scan():
                 if query.where is not None:
-                    mask = filter_mask(query.where, batch)
-                    if not mask.any():
+                    with spans.span(spans.ENGINE_FILTER,
+                                    rows=batch.num_rows):
+                        mask = filter_mask(query.where, batch)
+                        kept = int(np.count_nonzero(mask))
+                    if not kept:
                         continue
-                    if mask.all():
-                        out = batch.select(names)       # zero-copy projection
-                    else:
-                        out = batch.take(np.flatnonzero(mask)).select(names)
+                    with spans.span(spans.ENGINE_TAKE, rows=kept):
+                        if kept == batch.num_rows:
+                            out = batch.select(names)   # zero-copy projection
+                        else:
+                            out = batch.take(np.flatnonzero(mask)).select(
+                                names)
                 else:
-                    out = batch.select(names)           # zero-copy projection
+                    with spans.span(spans.ENGINE_TAKE, rows=batch.num_rows):
+                        out = batch.select(names)       # zero-copy projection
                 if remaining is not None:
                     if remaining <= 0:
                         return
@@ -108,7 +116,8 @@ class Engine:
         accs = [_Accumulator(item) for item in query.select]
         for batch in table.scan():
             if query.where is not None:
-                mask = filter_mask(query.where, batch)
+                with spans.span(spans.ENGINE_FILTER, rows=batch.num_rows):
+                    mask = filter_mask(query.where, batch)
             else:
                 mask = None
             for acc in accs:

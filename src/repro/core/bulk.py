@@ -24,6 +24,7 @@ from .recordbatch import Column, RecordBatch
 from .schema import Schema
 
 _EMPTY_U8 = np.zeros(0, dtype=np.uint8)
+SEGMENT_ALIGN = 64   # bytes: a segment's offset in its receive region
 
 
 @dataclasses.dataclass(frozen=True)
@@ -112,12 +113,20 @@ def allocate_like(descs: Sequence[SegmentDesc], pin: bool = False) -> BulkHandle
     """Client side: allocate a write-only local bulk with the same layout as
     a remote handle ("allocate a similar layout of buffers as on the server").
 
-    ``pin=True`` faults the pages in at allocation time (zero-fill), the way
-    RDMA registration must before the NIC can target the buffer — the honest
-    per-batch cost a registered buffer pool amortizes away."""
-    alloc = np.zeros if pin else np.empty
-    segs = tuple(alloc(d.nbytes // np.dtype(d.dtype).itemsize, dtype=d.dtype)
-                 for d in descs)
+    One receive region per batch: a single buffer holds every segment, each
+    a view at an offset rounded up to :data:`SEGMENT_ALIGN` bytes with its
+    exact size and dtype, so the pulled batch lands in HBM as one transfer
+    (``device_transport.batch_to_device``). ``pin=True`` faults the pages
+    in at allocation time (zero-fill), the way RDMA registration must
+    before the NIC can target the buffer — the honest per-batch cost a
+    registered buffer pool amortizes away."""
+    offsets, end = [], 0
+    for d in descs:
+        offsets.append(end)
+        end += -(-d.nbytes // SEGMENT_ALIGN) * SEGMENT_ALIGN
+    region = (np.zeros if pin else np.empty)(end, dtype=np.uint8)
+    segs = tuple(region[o:o + d.nbytes].view(d.dtype)
+                 for o, d in zip(offsets, descs))
     return BulkHandle(str(_uuid.uuid4()), tuple(descs), "write_only", segments=segs)
 
 

@@ -22,12 +22,17 @@ span                   opens around                                        args 
 ``thallus.sink``       ``ThallusClient.do_rdma``: the call to the sink     ``rows``
                        (the consumer's code)
 ``thallus.finalize``   ``ThallusServer.finalize``                          --
-``thallus.land``       ``core.device_transport.batch_to_device``: the      ``rows``, ``columns``
-                       per-column ``device_put`` loop                      (transfers), ``bytes``
+``thallus.land``       ``core.device_transport.batch_to_device``, after    ``rows``, ``columns``,
+                       the choice of path: validation, then the region's   ``transfers``,
+                       one transfer and split, or a put per column         ``bytes``
 ``engine.plan``        ``Engine.execute``: parse and catalog lookup        --
 ``engine.filter``      ``filter_mask`` of one batch (scan or aggregate)    ``rows`` (scanned)
 ``engine.take``        the gather or projection of one batch's kept rows   ``rows`` (kept)
 =====================  ==================================================  ====================
+
+In ``thallus.land``, ``columns`` counts the batch's columns and
+``transfers`` its host-to-HBM transfers: 1 where the batch lands from its
+receive region, one per column otherwise.
 
 Nesting on the query's thread: ``scan`` holds ``init_scan`` (which holds
 ``engine.plan``), ``iterate`` and ``finalize``; ``iterate`` holds, per

@@ -1,7 +1,9 @@
 """Compile rehearsals: every Pallas kernel of the main path, lowered by
 Mosaic and compiled for a described (not attached) TPU v5e at the sizes
-``chip_smoke.py``'s kernels phase runs. Nothing executes; what the chip's
-compiler would refuse (unaligned blocks, layouts, VMEM) fails here.
+``chip_smoke.py``'s kernels phase runs, and the landing path's region
+split at the widest batch of the TPC-H projection. Nothing executes; what
+the chip's compiler would refuse (unaligned blocks, layouts, VMEM) fails
+here.
 
 The topology is described inside a fixture, never at import, so every test
 worker collects the same tests and only the one running this file loads
@@ -10,9 +12,11 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.device_transport import _split
 from repro.kernels.attention.attention import _flash_attention
 from repro.kernels.pack.pack import _pack_tiles, _unpack_tiles
 from repro.kernels.take.take import _bitmap_expand, _take_rows
@@ -85,3 +89,16 @@ def test_flash_attention_compiles_for_v5e(one_chip):
     qkv = _shape(one_chip, (32, 2048, 64), jnp.bfloat16)
     _assert_mosaic(_flash_attention.lower(qkv, qkv, qkv, causal=True,
                                           interpret=False))
+
+
+def test_region_split_compiles_for_v5e(one_chip):
+    # all 15 LINEITEM columns of a 131,072-row batch: 11 int32, 4 uint8
+    rows, dtypes = 131072, [np.dtype(np.int32)] * 11 + [np.dtype(np.uint8)] * 4
+    layout, words = [], 0
+    for dtype in dtypes:
+        layout.append((words, rows, dtype))
+        words += rows * dtype.itemsize // 4
+    compiled = _split.lower(_shape(one_chip, (words,), jnp.uint32),
+                            tuple(layout)).compile()
+    outs = compiled.out_info
+    assert [(o.shape, o.dtype) for o in outs] == [((rows,), d) for d in dtypes]

@@ -7,6 +7,7 @@ from repro.core import (Fabric, FabricConfig, RpcTransport, ThallusTransport,
                         allocate_like, assemble_batch, batch_from_pydict,
                         expose_batch, pack, schema, serialized_size,
                         size_vectors, unpack)
+from repro.core.transport import rdma_pull_batch
 
 
 @pytest.fixture
@@ -69,6 +70,48 @@ def test_allocate_like_and_assemble(batch):
             dst.view(np.uint8).reshape(-1)[:] = src.view(np.uint8).reshape(-1)
     out = assemble_batch(batch.schema, batch.num_rows, local.segments)
     assert out.to_pydict() == batch.to_pydict()
+
+
+def test_allocate_like_gives_one_region(batch):
+    """Every segment is a view into one buffer at a 64-byte-aligned offset,
+    with its exact size and dtype, and no two segments overlap."""
+    descs = expose_batch(batch).descs
+    local = allocate_like(descs)
+    region = local.segments[0].base
+    assert isinstance(region, np.ndarray)
+    start = region.__array_interface__["data"][0]
+    spans = []
+    for seg, d in zip(local.segments, descs):
+        assert seg.base is region
+        assert seg.nbytes == d.nbytes and seg.dtype == np.dtype(d.dtype)
+        offset = seg.__array_interface__["data"][0] - start
+        assert offset % 64 == 0
+        spans.append((offset, offset + seg.nbytes))
+    spans.sort()
+    assert all(a_end <= b for (_, a_end), (b, _) in zip(spans, spans[1:]))
+    assert spans[-1][1] <= region.nbytes
+
+
+def test_allocate_like_pin_zero_fills(batch):
+    local = allocate_like(expose_batch(batch).descs, pin=True)
+    assert not local.segments[0].base.any()
+    assert all(not s.view(np.uint8).any() for s in local.segments)
+
+
+def test_pull_assembles_zero_copy_over_the_region(batch):
+    """A pull through ``rdma_pull_batch`` lands in the region, and the
+    assembled batch's buffers are views of it."""
+    got, local, _ = rdma_pull_batch(Fabric(), batch.schema, batch.num_rows,
+                                    expose_batch(batch))
+    assert got.to_pydict() == batch.to_pydict()
+    region = local.segments[0].base
+    for ci, col in enumerate(got.columns):
+        assert col.values.base is region
+        assert np.shares_memory(col.values, local.segments[3 * ci])
+        if col.offsets is not None:
+            assert np.shares_memory(col.offsets, local.segments[3 * ci + 1])
+        if col.validity is not None:
+            assert col.validity is local.segments[3 * ci + 2]
 
 
 def test_transport_parity(batch):
